@@ -11,10 +11,10 @@ settings and publishes one row per point:
     point per aggregate mode;
   * ``device_steps_per_s`` + the loss trajectory (compile excluded).
 
-Each point runs in a fresh subprocess (same discipline as
-bench_sharded_scaling): the host device count must be fixed before the
-first jax backend init, and a fresh process also keeps the per-point
-compile caches honest.
+All points run in one process, one FL device per device present (a chip
+belongs to one process at a time).  On the CPU (``JAX_PLATFORMS=cpu``)
+``--m-devices`` fixes the host mesh size before the backend starts;
+``benchmarks/run.py`` does the same for its own process.
 
 CI runs the smoke preset (same arch family, tiny dims) and gates the rows
 against the committed BENCH_100m_baseline.json: wire-bytes ceiling and
@@ -23,16 +23,17 @@ full ~128M-parameter sweep is a manual run:
 
     PYTHONPATH=src python -m benchmarks.bench_100m --preset full --rounds 12
 
-Timings use backend="exact": Pallas interpret mode on CPU is a parity
-backend, 10-30x slower than the compiled oracle (ARCHITECTURE.md §12) --
-routing through it would benchmark the interpreter, not the algorithm.
+Timings use backend="exact": on the CPU the Pallas kernels run in
+interpret mode, a parity backend 10-30x slower than the compiled oracle
+(ARCHITECTURE.md §12) -- routing through it would benchmark the
+interpreter, not the algorithm.  Rates from a CPU run are not device
+numbers.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
-import subprocess
 import sys
 
 from .common import emit
@@ -51,11 +52,8 @@ POINTS = (
 HLO_MODES = ("sparse_gather", "bucket_sparse", "dense_masked")
 
 
-def _worker(aggregate: str, sparsity: tuple, preset: str, m_devices: int,
-            rounds: int, seq: int, local_lr: float, with_hlo: bool) -> None:
-    from repro.launch.compat import force_host_device_count
-    force_host_device_count(m_devices)     # before first backend init
-    import jax
+def _point(aggregate: str, sparsity: tuple, preset: str, m_devices: int,
+           rounds: int, seq: int, local_lr: float, with_hlo: bool) -> dict:
     import jax.numpy as jnp
     from repro.models.paper_models import make_task
 
@@ -89,37 +87,24 @@ def _worker(aggregate: str, sparsity: tuple, preset: str, m_devices: int,
         row["collective_bytes_hlo"] = collective_bytes_from_hlo(text)
         row["hlo_flops"] = cost.flops
         row["hlo_bytes"] = cost.bytes
-    print(json.dumps(row))
+    return row
 
 
-def _spawn(aggregate: str, sparsity: tuple, preset: str, m_devices: int,
-           rounds: int, seq: int, local_lr: float, with_hlo: bool) -> dict:
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_100m", "--worker",
-         "--aggregate", aggregate,
-         "--sparsity", ",".join(str(f) for f in sparsity),
-         "--preset", preset, "--m-devices", str(m_devices),
-         "--rounds", str(rounds), "--seq", str(seq),
-         "--local-lr", str(local_lr)]
-        + ([] if with_hlo else ["--no-hlo"]),
-        capture_output=True, text=True, env=os.environ.copy(), timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"100m bench worker ({aggregate}, {sparsity}) failed:\n"
-            + out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+def run(preset: str = "smoke", m_devices: int | None = None,
+        rounds: int = 6, seq: int = 32, local_lr: float = 5e-3,
+        with_hlo: bool = True, emit_csv: bool = True) -> dict:
+    """Every frontier point in this process; ``m_devices`` defaults to the
+    devices present."""
+    import jax
 
-
-def run(preset: str = "smoke", m_devices: int = 4, rounds: int = 6,
-        seq: int = 32, local_lr: float = 5e-3, with_hlo: bool = True,
-        emit_csv: bool = True) -> dict:
+    m_devices = m_devices or len(jax.devices())
     rows = []
     hlo_done: set = set()
     for aggregate, sparsity in POINTS:
         hlo = (with_hlo and aggregate in HLO_MODES
                and aggregate not in hlo_done)
         hlo_done.add(aggregate)
-        row = _spawn(aggregate, sparsity, preset, m_devices, rounds, seq,
+        row = _point(aggregate, sparsity, preset, m_devices, rounds, seq,
                      local_lr, hlo)
         rows.append(row)
         dense = row["param_count"] * 4
@@ -131,29 +116,31 @@ def run(preset: str = "smoke", m_devices: int = 4, rounds: int = 6,
                  f"wire_bytes={row['wire_bytes_per_round_per_device']};"
                  f"vs_dense={dense / wire:.0f}x;"
                  f"loss_decrease={row['loss_decrease']}")
-    return {"bench": "lgc_100m", "rows": rows}
+    return {"bench": "lgc_100m", "device": jax.devices()[0].device_kind,
+            "rows": rows}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--worker", action="store_true")
-    ap.add_argument("--aggregate", default="sparse_gather")
-    ap.add_argument("--sparsity", default="0.01,0.02,0.02")
     ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])
-    ap.add_argument("--m-devices", type=int, default=4)
+    ap.add_argument("--m-devices", type=int, default=None,
+                    help="FL devices (default: every device present; on "
+                         "the CPU, the host mesh size)")
     ap.add_argument("--rounds", type=int, default=6)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--local-lr", type=float, default=5e-3)
     ap.add_argument("--no-hlo", action="store_true")
     ap.add_argument("--out", default="BENCH_100m.json")
     args = ap.parse_args(argv)
-    sparsity = tuple(float(x) for x in args.sparsity.split(","))
-    if args.worker:
-        _worker(args.aggregate, sparsity, args.preset, args.m_devices,
-                args.rounds, args.seq, args.local_lr, not args.no_hlo)
-        return 0
+    if (args.m_devices
+            and os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"):
+        from repro.launch.compat import force_host_device_count
+        force_host_device_count(args.m_devices)   # before backend init
+    from repro.launch.compat import enable_compile_cache
+    enable_compile_cache()
     result = run(preset=args.preset, m_devices=args.m_devices,
-                 rounds=args.rounds, seq=args.seq, with_hlo=not args.no_hlo)
+                 rounds=args.rounds, seq=args.seq, local_lr=args.local_lr,
+                 with_hlo=not args.no_hlo)
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     print(f"wrote {args.out} ({len(result['rows'])} rows)")

@@ -13,10 +13,12 @@ fixed fleet (default M=256) and reports two throughputs per row:
   window IS the engine hot loop, and XLA compile time (~10s, independent of
   D) would otherwise swamp the mesh signal at bench budgets.
 
-Each D runs in a fresh subprocess because the host device count
+This is a CPU study: each D runs in a fresh subprocess on the CPU backend
+(``JAX_PLATFORMS=cpu``), because the host device count
 (``--xla_force_host_platform_device_count``) must be fixed before jax
-imports.  ``--out`` (and ``benchmarks/run.py``) writes BENCH_sharded.json
-for CI artifact upload.
+imports -- so the children never need a chip, whatever the parent holds.
+Its rates are XLA:CPU numbers, not device numbers.  ``--out`` (and
+``benchmarks/run.py``) writes BENCH_sharded.json for CI artifact upload.
 
 Read the scaling ratio against ``physical_cores`` and ``cpu_util`` in the
 JSON: D virtual host devices cannot beat the machine's core count, and this
@@ -123,7 +125,8 @@ def _spawn(n_devices: int, m: int, rounds: int, engine: str,
          "--worker", "--devices", str(n_devices), "--m", str(m),
          "--rounds", str(rounds), "--engine", engine,
          "--k-windows", str(k_windows)],
-        capture_output=True, text=True, env=os.environ.copy(), timeout=3600)
+        capture_output=True, text=True,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=3600)
     if out.returncode != 0:
         raise RuntimeError(f"sharded bench worker (D={n_devices}) failed:\n"
                            + out.stderr[-2000:])

@@ -12,11 +12,18 @@ Every benchmark runs through :func:`_step`, which prints the per-benchmark
 wall time to stderr and, on failure, exits naming the failing benchmark --
 so a red bench-smoke CI lane is diagnosable from the last log line instead
 of a bare traceback.
+
+Everything that needs the device runs in this one process (a chip belongs
+to one process at a time).  The only children are the mesh-scaling
+study's, which run on the CPU by design (bench_sharded_scaling).  On the
+CPU (``JAX_PLATFORMS=cpu``) the process exposes a host mesh of 4 (smoke)
+or 8 virtual devices for the 100M frontier.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -61,6 +68,13 @@ def main() -> None:
                     help="path for the 100M-stack wire/throughput frontier")
     args = ap.parse_args()
 
+    from repro.launch.compat import (enable_compile_cache,
+                                     force_host_device_count)
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        # the 100M frontier's FL devices, as a CPU host mesh
+        force_host_device_count(4 if args.smoke else 8)
+    enable_compile_cache()
+
     from benchmarks import (bench_100m, bench_async,
                             bench_compressor_throughput,
                             bench_controller_scaling,
@@ -90,7 +104,7 @@ def main() -> None:
         asynch = _step("async", bench_async.run,
                        m=8, rounds=60, n_train=1500)             # aggregators
         hundredm = _step("lgc_100m", bench_100m.run,
-                         preset="smoke", m_devices=4, rounds=6)  # 100M stack
+                         preset="smoke", rounds=6)               # 100M stack
         _step("fig3_lr_mnist", bench_fig3_lr_mnist.run,
               model="lr", rounds=40, n_train=1200)
     else:
@@ -108,7 +122,7 @@ def main() -> None:
         asynch = _step("async", bench_async.run,
                        m=16, rounds=120, n_train=2000)
         hundredm = _step("lgc_100m", bench_100m.run,
-                         preset="smoke", m_devices=8, rounds=12)
+                         preset="smoke", rounds=12)
         _step("fig3_lr_mnist", bench_fig3_lr_mnist.run,
               model="lr", rounds=100, n_train=2000)              # Fig 3
         _step("fig4_cnn_mnist", bench_fig3_lr_mnist.run,

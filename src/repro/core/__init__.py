@@ -4,15 +4,6 @@ The modules below are bound together by the engine-equivalence ladder
 (loop ~ batched == sharded History; docs/ARCHITECTURE.md §1) -- each
 module's docstring names the invariant it participates in and the test
 that enforces it."""
-from repro.launch.compat import ensure_fast_cpu_runtime
-
-# Before anything can touch the backend: on jaxlib 0.4.3x CPU, swap the
-# thunk runtime for the legacy one -- while-loop (lax.scan) bodies run ~37x
-# faster on small-core hosts (see the function's docstring and
-# docs/ARCHITECTURE.md §10).  No-op on other jaxlibs or under
-# REPRO_XLA_THUNK_RUNTIME=1.
-ensure_fast_cpu_runtime()
-
 from .compressor import (LGCCompressor, flatten_tree, lgc_compress, lgc_layers,
                          lgc_compress_topk, lgc_compress_traced,
                          top_alpha_beta, top_k, tree_size, unflatten_like,

@@ -341,8 +341,8 @@ def per_layer_candidates(u: Array, slices: Sequence[tuple[str, int, int]],
 def per_layer_candidates_hist(u: Array,
                               slices: Sequence[tuple[str, int, int]],
                               budgets: Array,
-                              pallas_min_elems: int = PALLAS_MIN_ELEMS,
-                              interpret: bool = True) -> Array:
+                              pallas_min_elems: int = PALLAS_MIN_ELEMS
+                              ) -> Array:
     """Histogram-threshold candidate mask (the Pallas backend's selection).
 
     Each layer's threshold comes from the 256-bin magnitude histogram --
@@ -361,8 +361,8 @@ def per_layer_candidates_hist(u: Array,
         seg = u[lo:hi]
         cum = budgets[i].reshape((1,)).astype(jnp.int32)
         if hi - lo >= pallas_min_elems:
-            mx = maxabs(seg, interpret=interpret)
-            counts = histogram(seg, mx, interpret=interpret)
+            mx = maxabs(seg)
+            counts = histogram(seg, mx)
             mx = mx.reshape(())
         else:
             mx = hist_maxabs(seg)

@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the LGC compression hot path + decode attention.
 
 Kernels (each validated against ref.py oracles in interpret mode,
-tests/test_kernels.py):
+tests/test_kernels.py, and compiled for a described TPU v5e,
+tests/test_tpu_compile.py; :func:`platform.resolve_interpret` runs them
+compiled on TPU and interpreted on CPU):
   topk_threshold   -- maxabs + 256-bin magnitude histogram (2-pass Top_k)
   layered_sparsify -- fused layered sparsify + error-feedback update
   swa_attention    -- sliding-window flash decode attention (long_500k)

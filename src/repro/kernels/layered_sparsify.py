@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
 from .topk_threshold import LANES, _as_rows
 
 
@@ -49,7 +50,8 @@ def _sparsify_ef_kernel(e_ref, d_ref, thr_ref, recv_ref, g_ref, enew_ref, *,
                    static_argnames=("block_rows", "interpret"))
 def sparsify_ef(e: jax.Array, delta: jax.Array, thr: jax.Array,
                 received: jax.Array, *, block_rows: int = 64,
-                interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                interpret: bool | None = None
+                ) -> tuple[jax.Array, jax.Array]:
     """Fused layered sparsify + error-feedback update on flat vectors.
 
     Args:
@@ -81,7 +83,7 @@ def sparsify_ef(e: jax.Array, delta: jax.Array, thr: jax.Array,
             jax.ShapeDtypeStruct(er.shape, jnp.float32),
             jax.ShapeDtypeStruct(er.shape, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(er, dr, thr.reshape(1, -1).astype(jnp.float32),
       received.reshape(1, -1).astype(jnp.int32))
     return g.reshape(-1)[:d], e_new.reshape(-1)[:d]

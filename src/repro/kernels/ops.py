@@ -26,16 +26,16 @@ from .topk_threshold import histogram, maxabs, thresholds_from_counts
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def lgc_compress_hist(e: jax.Array, delta: jax.Array, cum_ks: jax.Array,
                       received: jax.Array, *, block_rows: int = 64,
-                      interpret: bool = True) -> tuple[jax.Array, jax.Array]:
-    """Histogram-LGC with error feedback. Returns (g, e_new), f32 (D,)."""
-    u = None  # never materialised in HBM; kernels recompute e + delta
-    del u
-    # statistics passes operate on u = e + delta; compute it blockwise too by
-    # passing the sum lazily -- for stats we accept one fused add here since
-    # XLA fuses it into the pallas input copy.
-    u_stats = (e.astype(jnp.float32) + delta.astype(jnp.float32))
-    m = maxabs(u_stats, block_rows=block_rows, interpret=interpret)
-    counts = histogram(u_stats, m, block_rows=block_rows, interpret=interpret)
+                      interpret: bool | None = None
+                      ) -> tuple[jax.Array, jax.Array]:
+    """Histogram-LGC with error feedback. Returns (g, e_new), f32 (D,).
+
+    The statistics passes read u = e + delta, one fused add that XLA folds
+    into the kernels' input copy; the sparsify pass recomputes u in VMEM.
+    """
+    u = e.astype(jnp.float32) + delta.astype(jnp.float32)
+    m = maxabs(u, block_rows=block_rows, interpret=interpret)
+    counts = histogram(u, m, block_rows=block_rows, interpret=interpret)
     thr = thresholds_from_counts(counts, m, cum_ks)
     return sparsify_ef(e, delta, thr, received, block_rows=block_rows,
                        interpret=interpret)

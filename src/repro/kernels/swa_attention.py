@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
+
 
 def _swa_decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, *,
                        chunk: int, window: int):
@@ -54,7 +56,7 @@ def _swa_decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, *,
                    static_argnames=("chunk", "interpret"))
 def swa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                length: jax.Array, *, chunk: int = 512,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool | None = None) -> jax.Array:
     """Flash decode attention over a sliding-window cache.
 
     Args:
@@ -81,5 +83,5 @@ def swa_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, dh), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, len2)

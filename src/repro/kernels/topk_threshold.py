@@ -9,9 +9,14 @@ tests/test_kernels.py::TestMaxAbs/TestHistogram):
   host    : thresholds from the descending histogram CDF (256 scalars)
 
 Both kernels view the flat gradient as a (rows, 128)-shaped matrix -- the
-TPU vector-lane layout -- and tile over row blocks held in VMEM.  The
-histogram scatter is expressed as a one-hot contraction (bins x lanes),
-which maps onto the VPU instead of a serial scatter.
+TPU vector-lane layout -- and tile over row blocks held in VMEM.  Neither
+stores a scalar to VMEM: each accumulates into lane-shaped (8, 128) tiles
+(one per bin for the histogram) and the final cross-lane reduction runs
+outside the kernel.  The histogram scale ``256 / maxabs`` is computed
+outside too, with the oracle's own expression, and enters the kernel
+broadcast over one row of lanes (a vector operand, so the kernel also
+batches under ``jax.vmap``) -- kernel and oracle bin every element
+identically.
 
 Grid iteration on TPU is sequential per core, so both kernels accumulate
 into their (revisited) output block across grid steps; ``@pl.when(step==0)``
@@ -25,34 +30,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
+
 N_BINS = 256
 LANES = 128
+SUBLANES = 8
+
+
+def _sublane_tiles(a: jax.Array) -> jax.Array:
+    """(rows, 128) -> (rows // 8, 8, 128): whole vreg tiles, no relayout."""
+    return a.reshape(-1, SUBLANES, LANES)
 
 
 def _maxabs_kernel(x_ref, o_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    block_max = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
-    o_ref[0, 0] = jnp.maximum(o_ref[0, 0], block_max)
+    a = _sublane_tiles(jnp.abs(x_ref[...].astype(jnp.float32)))
+    o_ref[...] = jnp.maximum(o_ref[...], jnp.max(a, axis=0))
 
 
-def _hist_kernel(x_ref, maxabs_ref, o_ref):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+def _hist_kernel(scale_ref, x_ref, o_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    a = jnp.abs(x_ref[...].astype(jnp.float32))        # (rows, 128)
-    m = maxabs_ref[0, 0]
-    scale = jnp.where(m > 0, N_BINS / m, 0.0)
-    bins = jnp.clip((a * scale).astype(jnp.int32), 0, N_BINS - 1)
-    # one-hot contraction: counts[b] = sum_ij [bins_ij == b]
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (N_BINS, 1, 1), 0)
-    onehot = (bins[None, :, :] == bin_ids).astype(jnp.int32)
-    o_ref[...] += jnp.sum(onehot, axis=(1, 2))[None, :]
+    a = jnp.abs(x_ref[...].astype(jnp.float32))            # (rows, 128)
+    bins = jnp.clip((a * scale_ref[...]).astype(jnp.int32), 0, N_BINS - 1)
+    bins = _sublane_tiles(bins)
+
+    # o_ref[b] holds bin b's per-lane partial counts as one (8, 128) tile
+    def count_bin(b, carry):
+        o_ref[b] += jnp.sum((bins == b).astype(jnp.int32), axis=0)
+        return carry
+    jax.lax.fori_loop(0, N_BINS, count_bin, 0)
 
 
 def _as_rows(x: jax.Array, block_rows: int) -> tuple[jax.Array, int, int]:
@@ -67,35 +77,40 @@ def _as_rows(x: jax.Array, block_rows: int) -> tuple[jax.Array, int, int]:
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def maxabs(x: jax.Array, *, block_rows: int = 64,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool | None = None) -> jax.Array:
     """max |x| over a flat vector. Returns (1,1) f32."""
     xr, n_blocks, _ = _as_rows(x, block_rows)
-    return pl.pallas_call(
+    tile = pl.pallas_call(
         _maxabs_kernel,
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(xr)
+    return jnp.max(tile).reshape(1, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def histogram(x: jax.Array, maxabs_val: jax.Array, *, block_rows: int = 64,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """256-bin |x| histogram; padding-corrected. Returns (256,) int32."""
     xr, n_blocks, pad = _as_rows(x, block_rows)
-    counts = pl.pallas_call(
+    m = maxabs_val.reshape(())
+    scale = jnp.where(m > 0, N_BINS / m, 0.0)     # == ref.hist_counts
+    tiles = pl.pallas_call(
         _hist_kernel,
         grid=(n_blocks,),
         in_specs=[
+            pl.BlockSpec((1, LANES), lambda i: (0, 0)),
             pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, N_BINS), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, N_BINS), jnp.int32),
-        interpret=interpret,
-    )(xr, maxabs_val.reshape(1, 1))[0]
+        out_specs=pl.BlockSpec((N_BINS, SUBLANES, LANES),
+                               lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N_BINS, SUBLANES, LANES), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(jnp.full((1, LANES), scale, jnp.float32), xr)
+    counts = jnp.sum(tiles, axis=(1, 2))
     return counts.at[0].add(-pad)  # zero padding lands in bin 0
 
 

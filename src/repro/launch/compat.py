@@ -1,63 +1,41 @@
-"""Version-compat shims over the jax sharding API drift (0.4.x vs >= 0.5).
+"""Process setup and thin sharding helpers over the installed jax (0.9).
 
-The launch stack targets the modern explicit-sharding surface
-(``jax.make_mesh(axis_types=...)``, ``jax.set_mesh``, ``jax.shard_map`` with
-``axis_names``/``check_vma``), but the pinned CI container ships jax 0.4.37
-where those spell ``jax.make_mesh`` without axis types, the mesh
-resource-env context, and ``jax.experimental.shard_map`` with
-``auto``/``check_rep``.  Everything here is a thin feature-detected
-dispatch -- no behaviour change on new jax.
+* :func:`enable_compile_cache` -- where JAX keeps compiled programs; the
+  entry points (``chip_smoke.py``, the examples, ``benchmarks/run.py``)
+  call it, the library never does on import.
+* :func:`force_host_device_count` -- a CPU host mesh of ``n`` virtual
+  devices, for CPU studies and tests.
+* :func:`make_mesh` / :func:`shardings` / :func:`shard_map` -- the
+  explicit-sharding surface the launch stack uses.
 
 The sharded FL engine (:class:`repro.core.fl_batched.ShardedEngine`) uses
-the fully-manual :func:`shard_map` path (``axis_names=None``), which maps to
-``auto=frozenset()`` on 0.4.x -- partial-auto is never required.  CI runs a
-{pinned, latest} jax matrix so drift in these shims surfaces the day a new
-jax releases, not when the pin moves.
+the fully-manual :func:`shard_map` path (``axis_names=None``); the LGC
+train step is manual over the FL axis and every size-1 axis.
 """
 from __future__ import annotations
 
 import os
+import pathlib
 from typing import Sequence
 
 import jax
 
+#: compile-cache home when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed
+#: path, because the path is part of the cache's key
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def ensure_fast_cpu_runtime() -> bool:
-    """Opt XLA:CPU out of the thunk runtime on the jaxlib 0.4.3x line.
 
-    The thunk runtime (default since jaxlib 0.4.32) executes ``while`` loop
-    bodies through a concurrent task scheduler whose dispatch overhead
-    dwarfs the actual compute on small-core hosts: the cnn_mnist sync
-    window (a 4-step ``lax.scan`` over vmapped conv grads) measures 26.2 s
-    per window on a 1-core container against 0.70 s with
-    ``--xla_cpu_use_thunk_runtime=false`` -- a 37x gap that made the CNN /
-    GRU tasks look compute-bound when they were scheduler-bound
-    (docs/ARCHITECTURE.md §10).
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-    Appends the flag to ``XLA_FLAGS`` (idempotently) so it takes effect at
-    the first backend initialisation.  Gated to jaxlib versions that still
-    ship the legacy runtime ([0.4.32, 0.5)): unknown XLA flags are a hard
-    startup error, so newer jaxlibs -- where the legacy runtime was removed
-    -- must not see it.  Set ``REPRO_XLA_THUNK_RUNTIME=1`` to keep the
-    thunk runtime (e.g. to benchmark it).  Returns True when the flag is
-    (already) applied.  Best-effort: if the backend is already initialised
-    the env change cannot take effect for this process.
+    ``JAX_COMPILATION_CACHE_DIR`` wins when it is set; otherwise the cache
+    lives at ``<repo>/.jax_cache`` (git-ignored).  Call it once, before the
+    first compile.
     """
-    flag = "--xla_cpu_use_thunk_runtime=false"
-    if flag in os.environ.get("XLA_FLAGS", ""):
-        return True
-    if os.environ.get("REPRO_XLA_THUNK_RUNTIME") == "1":
-        return False
-    try:
-        import jaxlib
-        ver = tuple(int(p) for p in jaxlib.__version__.split(".")[:3])
-    except Exception:
-        return False
-    if not ((0, 4, 32) <= ver < (0, 5, 0)):
-        return False
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " " + flag).strip()
-    return True
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        DEFAULT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def force_host_device_count(n: int) -> None:
@@ -67,51 +45,22 @@ def force_host_device_count(n: int) -> None:
     any pre-existing occurrence of the flag is dropped first, because XLA
     honours the LAST occurrence -- naively prepending would let an inherited
     environment value (e.g. the test-sharded CI lane's =8) silently win.
-    Must run before the first jax backend initialisation in the process,
-    which is why the mesh-scaling bench workers apply it in a fresh
-    subprocess per device count.
+    Must run before the first jax backend initialisation in the process.
     """
     kept = [f for f in os.environ.get("XLA_FLAGS", "").split()
             if not f.startswith("--xla_force_host_platform_device_count=")]
     os.environ["XLA_FLAGS"] = " ".join(
         kept + [f"--xla_force_host_platform_device_count={n}"])
-    # callers invoke this before their first backend init (fresh worker
-    # processes), which is also the last safe moment for the CPU runtime
-    # flag -- piggyback so subprocess workers that import jax before
-    # repro.core still get the fast runtime
-    ensure_fast_cpu_runtime()
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with Auto axis types when the API supports them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def set_mesh(mesh: "jax.sharding.Mesh") -> "jax.sharding.Mesh":
-    """Install ``mesh`` as the ambient mesh for subsequent jit/pjit calls.
-
-    New jax: ``jax.set_mesh``.  Old jax: enter the legacy resource-env
-    context (and leave it open -- callers use this once at program setup,
-    matching ``jax.set_mesh`` semantics, not as a scoped context).
-    """
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-    else:
-        mesh.__enter__()
-    return mesh
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shardings(mesh, spec_tree):
-    """PartitionSpec tree -> NamedSharding tree for jit in/out_shardings.
-
-    Every jax version accepts Sharding objects; 0.4.x ``jax.jit`` accepts
-    *only* those (bare PartitionSpecs raise), so call sites route specs
-    through this before handing them to jit.
-    """
+    """PartitionSpec tree -> NamedSharding tree for jit in/out_shardings."""
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), spec_tree,
@@ -121,17 +70,11 @@ def shardings(mesh, spec_tree):
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
     """``jax.shard_map`` manual over ``axis_names`` only (auto elsewhere).
 
-    Old jax spells partial-manual as ``auto=<complement>`` on
-    ``jax.experimental.shard_map.shard_map``; replica/vma checking is
-    disabled on both paths (the LGC step's gather patterns trip it).
+    Replica/vma checking is disabled (the LGC step's gather patterns trip
+    it).
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = {"check_vma": False}
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - set(axis_names or mesh.axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
+    kwargs = {"check_vma": False}
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -58,7 +58,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         cfg = dataclasses.replace(cfg, **cfg_overrides)
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.devices.size
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
     fl_ax = fl_axis_name(mesh)
     if mode in ("lgc", "lgc_sparse", "lgc_bucket", "fedavg") and cfg.fsdp:
         # (a) FL devices must hold whole replicas along the FL axis;

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import jax
 
-from .compat import make_mesh, set_mesh  # noqa: F401  (set_mesh re-exported)
+from .compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

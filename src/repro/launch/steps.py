@@ -67,10 +67,10 @@ class LGCStepConfig:
     # through the fused kernels.lgc_compress_hist pipeline (bit-identical
     # to the kref oracle -- tests/test_kernels.py); smaller leaves stay on
     # the oracle either way.  "exact" keeps everything on the oracle.
-    # pallas_interpret=True is the CPU parity mode; flip off on real TPU.
+    # The kernels run compiled on TPU and interpreted on CPU
+    # (kernels.platform.resolve_interpret).
     backend: str = "exact"
     pallas_min_elems: int = PALLAS_MIN_ELEMS
-    pallas_interpret: bool = True
 
     @property
     def n_channels(self) -> int:
@@ -144,8 +144,8 @@ def _leaf_cum_ks(size: int, sparsity: Sequence[float]) -> jnp.ndarray:
 
 def _compress_leaf_dense(e: Array, delta: Array, sparsity, recv: Array,
                          *, backend: str = "exact",
-                         pallas_min_elems: int = PALLAS_MIN_ELEMS,
-                         interpret: bool = True) -> tuple[Array, Array]:
+                         pallas_min_elems: int = PALLAS_MIN_ELEMS
+                         ) -> tuple[Array, Array]:
     """Histogram-LGC on one tensor; returns (g, e_new) with leaf's shape.
 
     ``recv`` is this FL device's (C,) per-channel delivery mask: masked
@@ -159,8 +159,7 @@ def _compress_leaf_dense(e: Array, delta: Array, sparsity, recv: Array,
     d_flat = delta.reshape(-1).astype(jnp.float32)
     cum_ks = _leaf_cum_ks(d_flat.shape[0], sparsity)
     if backend == "pallas" and d_flat.shape[0] >= pallas_min_elems:
-        g, e_new = lgc_compress_hist(e_flat, d_flat, cum_ks, recv,
-                                     interpret=interpret)
+        g, e_new = lgc_compress_hist(e_flat, d_flat, cum_ks, recv)
     else:
         g, e_new = kref.hist_lgc_compress(e_flat, d_flat, cum_ks, recv)
     return g.reshape(shape), e_new.reshape(shape)
@@ -351,7 +350,17 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
     The FedAvg baseline (aggregate="none") has no channels and ignores it.
     """
     fl_ax = fl_axis_name(mesh)
-    n_fl = dict(zip(mesh.axis_names, mesh.devices.shape))[fl_ax]
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    n_fl = sizes[fl_ax]
+    # manual over the FL axis and every size-1 axis: a size-1 axis
+    # partitions nothing, and Mosaic kernels lower only where no axis is
+    # left to automatic partitioning
+    manual = {fl_ax} | {a for a, n in sizes.items() if n == 1}
+    if step_cfg.backend == "pallas" and manual != set(mesh.axis_names):
+        raise ValueError(
+            f"backend='pallas' needs every mesh axis but the FL axis "
+            f"{fl_ax!r} to have size 1 (Pallas kernels cannot be "
+            f"partitioned automatically); mesh axes {sizes}")
     h = step_cfg.local_steps
     n_ch = step_cfg.n_channels
 
@@ -370,8 +379,7 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
         is_leaf=lambda x: isinstance(x, P))
 
     dense_kw = dict(backend=step_cfg.backend,
-                    pallas_min_elems=step_cfg.pallas_min_elems,
-                    interpret=step_cfg.pallas_interpret)
+                    pallas_min_elems=step_cfg.pallas_min_elems)
 
     def step(params, ef, batch, received=None):
         if received is None:
@@ -381,7 +389,7 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
             compat.shard_map, mesh=mesh,
             in_specs=(P(), P(fl_ax), batch_in_specs, P(fl_ax)),
             out_specs=(P(), P(fl_ax), P()),
-            axis_names={fl_ax})
+            axis_names=manual)
         def inner(params, ef_stack, batch, received):
             ef = jax.tree_util.tree_map(lambda x: x[0], ef_stack)
             recv = received[0].astype(jnp.int32)      # (C,) own channels

@@ -57,7 +57,7 @@ def main():
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh(args.devices, model=args.model_parallel)
-    compat.set_mesh(mesh)
+    jax.set_mesh(mesh)
 
     params = tf.init_params(cfg, jax.random.PRNGKey(0))
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))

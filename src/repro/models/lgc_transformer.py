@@ -87,8 +87,13 @@ class LGCTransformerTask:
         if self._built is not None:
             return self._built
         cfg = self.arch
+        present = len(jax.devices())
+        if self.n_devices > present:
+            raise ValueError(
+                f"{self.name}: m_devices={self.m_devices} x model_axis="
+                f"{self.model_axis} needs {self.n_devices} devices, but "
+                f"{present} are present")
         mesh = make_host_mesh(self.n_devices, model=self.model_axis)
-        compat.set_mesh(mesh)
         fl_ax = fl_axis_name(mesh)
         params = tf.init_params(cfg, jax.random.PRNGKey(self.seed))
         pipe = TokenPipeline(cfg.vocab_size, self.seq,
@@ -103,16 +108,18 @@ class LGCTransformerTask:
         ef = rules.place(init_ef_tree(params, self.m_devices,
                                       jnp.dtype(self.step_cfg.ef_dtype)),
                          especs, mesh)
+        recv_sharding = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec(fl_ax))
         step = jax.jit(
             make_lgc_train_step(cfg, mesh, self.step_cfg, bspecs,
                                 param_spec_tree=pspecs),
-            in_shardings=compat.shardings(
-                mesh, (pspecs, especs, bspecs,
-                       jax.sharding.PartitionSpec(fl_ax))),
+            in_shardings=compat.shardings(mesh, (pspecs, especs, bspecs))
+            + (recv_sharding,),
             donate_argnums=(0, 1))
         self._built = dict(mesh=mesh, fl_ax=fl_ax, params=params, ef=ef,
                            step=step, pipe=pipe, pspecs=pspecs,
-                           especs=especs, bspecs=bspecs)
+                           especs=especs, bspecs=bspecs,
+                           recv_sharding=recv_sharding)
         return self._built
 
     # -- scenario-driven channel availability -------------------------------
@@ -146,10 +153,13 @@ class LGCTransformerTask:
         b = self.build()
         params, ef, step, pipe = b["params"], b["ef"], b["step"], b["pipe"]
         base, dev_ids, carry = self._mask_state()
-        losses, t_steady = [], None
+        losses, t_steady, first_round_s = [], None, 0.0
         t0 = time.perf_counter()
         for i in range(steps):
             carry, received = self._round_mask(base, dev_ids, carry, i)
+            # the mask program's output is committed replicated; the step
+            # takes it split over the FL axis
+            received = jax.device_put(received, b["recv_sharding"])
             x, y = pipe.next_batch()
             params, ef, loss = step(params, ef,
                                     {"tokens": jnp.asarray(x),
@@ -157,6 +167,7 @@ class LGCTransformerTask:
             losses.append(float(loss))   # float() syncs the step
             if i == 0:
                 t_steady = time.perf_counter()   # exclude compile
+                first_round_s = t_steady - t0
             if log_every and (i % log_every == 0 or i == steps - 1):
                 print(f"[{self.name}] round {i:4d} loss {losses[-1]:.4f} "
                       f"({time.perf_counter() - t0:.0f}s)")
@@ -167,6 +178,9 @@ class LGCTransformerTask:
         self._built["params"], self._built["ef"] = params, ef
         return {
             "losses": losses,
+            # compile + first round; mean round after it
+            "first_round_s": first_round_s,
+            "steady_round_s": steady_s / (steps - 1) if steps > 1 else 0.0,
             "device_steps_per_s": (dev_steps / steady_s) if steady_s else 0.0,
             "wire_bytes_per_round_per_device": self.wire_bytes_per_round(),
             "param_count": self.param_count(),
@@ -191,7 +205,8 @@ def make_qwen2_100m_task(m_devices: int = 8, seed: int = 0,
     flattened gradients -- every matmul leaf above ``PALLAS_MIN_ELEMS``);
     ``preset="smoke"`` is the tiny same-shape variant for tests and CI.
     ``backend="pallas"`` routes the dense-path compression of the big
-    leaves through the fused Pallas pipeline (interpret mode on CPU).
+    leaves through the fused Pallas pipeline (compiled on TPU, interpreted
+    on CPU).
     """
     if arch is None:
         arch = (get_config("qwen2-100m") if preset == "full"

@@ -1,140 +1,37 @@
-"""Tests for the launch/compat version shims.
+"""Tests for the launch/compat process-setup helpers.
 
-``ensure_fast_cpu_runtime`` is the load-bearing PR-7 path: it decides,
-from the jaxlib version and the process environment, whether the
-``--xla_cpu_use_thunk_runtime=false`` flag is appended to ``XLA_FLAGS``
-before backend init (docs/ARCHITECTURE.md §10).  A wrong decision is
-either a 37x slowdown (flag missing on 0.4.3x) or a hard startup crash
-(unknown flag on >= 0.5), so the version gate's *boundaries* are pinned
-here with mocked jaxlib versions -- the function reads
-``jaxlib.__version__`` at call time, which is what makes it mockable.
+``force_host_device_count`` rewrites ``XLA_FLAGS`` so that XLA's CPU backend
+exposes a host mesh; XLA honours the LAST occurrence of a flag, so stale
+inherited values must be dropped, not shadowed.  ``enable_compile_cache``
+places JAX's persistent compilation cache: where the environment says, or
+at one fixed path in the repository -- the path is part of the cache key.
 """
 from __future__ import annotations
 
-import jaxlib
+import os
+import pathlib
+
+import jax
 import pytest
 
-from repro.launch.compat import (ensure_fast_cpu_runtime,
-                                 force_host_device_count)
+from repro.launch.compat import enable_compile_cache, force_host_device_count
 
-FLAG = "--xla_cpu_use_thunk_runtime=false"
 COUNT8 = "--xla_force_host_platform_device_count=8"
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture
 def clean_env(monkeypatch):
-    """No XLA_FLAGS, no opt-out: the decision rests on the version gate."""
     monkeypatch.delenv("XLA_FLAGS", raising=False)
-    monkeypatch.delenv("REPRO_XLA_THUNK_RUNTIME", raising=False)
     return monkeypatch
 
 
-class TestVersionGate:
-    """The flag applies exactly on [0.4.32, 0.5.0) -- the jaxlib line that
-    ships both runtimes.  Outside it the flag is unknown to XLA (hard
-    startup error), so both boundaries matter."""
-
-    @pytest.mark.parametrize("version,expected", [
-        ("0.4.31", False),    # pre-thunk-runtime: nothing to opt out of
-        ("0.4.32", True),     # first thunk-runtime release
-        ("0.4.37", True),     # the pinned CI container
-        ("0.4.38.dev20250101", True),   # dev builds parse by numeric prefix
-        ("0.5.0", False),     # legacy runtime removed; flag now fatal
-        ("0.6.1", False),
-    ])
-    def test_boundary(self, clean_env, version, expected):
-        clean_env.setattr(jaxlib, "__version__", version)
-        import os
-        assert ensure_fast_cpu_runtime() is expected
-        assert (FLAG in os.environ.get("XLA_FLAGS", "")) is expected
-
-    def test_unparseable_version_is_a_noop(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "weekly-nightly")
-        import os
-        assert ensure_fast_cpu_runtime() is False
-        assert "XLA_FLAGS" not in os.environ
-
-
-class TestOptOut:
-    def test_env_opt_out_wins_over_version(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        clean_env.setenv("REPRO_XLA_THUNK_RUNTIME", "1")
-        import os
-        assert ensure_fast_cpu_runtime() is False
-        assert "XLA_FLAGS" not in os.environ
-
-    def test_opt_out_only_honours_exactly_1(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        clean_env.setenv("REPRO_XLA_THUNK_RUNTIME", "0")
-        assert ensure_fast_cpu_runtime() is True
-
-
-class TestIdempotence:
-    def test_second_call_does_not_duplicate_the_flag(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.35")
-        import os
-        assert ensure_fast_cpu_runtime() is True
-        flags_after_first = os.environ["XLA_FLAGS"]
-        assert ensure_fast_cpu_runtime() is True
-        assert os.environ["XLA_FLAGS"] == flags_after_first
-        assert flags_after_first.count(FLAG) == 1
-
-    def test_flag_already_present_short_circuits_any_version(self, clean_env):
-        # a caller (or CI lane) that already set the flag wins outright,
-        # even on a jaxlib where the gate itself would say no
-        clean_env.setattr(jaxlib, "__version__", "0.5.0")
-        clean_env.setenv("XLA_FLAGS", f"--some_other_flag {FLAG}")
-        import os
-        before = os.environ["XLA_FLAGS"]
-        assert ensure_fast_cpu_runtime() is True
-        assert os.environ["XLA_FLAGS"] == before
-
-    def test_existing_xla_flags_content_is_preserved(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.33")
-        clean_env.setenv(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-        import os
-        assert ensure_fast_cpu_runtime() is True
-        flags = os.environ["XLA_FLAGS"].split()
-        assert "--xla_force_host_platform_device_count=8" in flags
-        assert FLAG in flags
-
-
 class TestForceHostDeviceCountComposition:
-    """The two env mutators must compose in EITHER order.
-
-    examples/train_100m_lgc.py used to do
-    ``os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_...")``,
-    which is a silent no-op whenever XLA_FLAGS is inherited (a CI lane or a
-    parent process that already ran ``ensure_fast_cpu_runtime``) -- the
-    8-device mesh build then fails with "Number of devices 1 must be >= 8".
-    These pins make that regression impossible to reintroduce quietly.
-    """
-
-    def test_force_after_ensure_keeps_runtime_flag(self, clean_env):
-        # the exact bit-rot scenario: runtime flag already in the env
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        import os
-        assert ensure_fast_cpu_runtime() is True
-        force_host_device_count(8)
-        flags = os.environ["XLA_FLAGS"].split()
-        assert COUNT8 in flags and FLAG in flags
-        assert flags.count(FLAG) == 1
-
-    def test_ensure_after_force_keeps_device_count(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        import os
-        force_host_device_count(8)
-        assert ensure_fast_cpu_runtime() is True
-        flags = os.environ["XLA_FLAGS"].split()
-        assert COUNT8 in flags and FLAG in flags
-        assert flags.count(COUNT8) == 1
+    """The rewrite composes with whatever XLA_FLAGS the process inherits."""
 
     def test_inherited_count_is_replaced_not_shadowed(self, clean_env):
         """XLA honours the LAST occurrence of the flag; stale inherited
         values must be dropped, not merely appended after."""
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        import os
         clean_env.setenv(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=2")
         force_host_device_count(8)
@@ -143,12 +40,28 @@ class TestForceHostDeviceCountComposition:
         assert "--xla_force_host_platform_device_count=2" not in flags
 
     def test_idempotent(self, clean_env):
-        clean_env.setattr(jaxlib, "__version__", "0.4.37")
-        import os
         force_host_device_count(8)
         first = os.environ["XLA_FLAGS"]
         force_host_device_count(8)
-        # flag ORDER may change (count is re-appended last, which XLA
-        # honours); the set of flags must not
         assert set(os.environ["XLA_FLAGS"].split()) == set(first.split())
         assert os.environ["XLA_FLAGS"].split().count(COUNT8) == 1
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def restore_cache_dir(self):
+        before = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_dir_is_used(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+    def test_unset_env_uses_fixed_repo_path(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = enable_compile_cache()
+        assert path == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path          # same on every call

@@ -27,7 +27,7 @@ class TestShardingRules:
             import jax
             from repro.configs import get_smoke_config, list_archs
             from repro.launch import compat
-            from repro.launch.mesh import make_host_mesh, set_mesh
+            from repro.launch.mesh import make_host_mesh
             from repro.launch import sharding_rules as rules
             from repro.models import transformer as tf
             mesh = make_host_mesh(8, model=2)
@@ -83,14 +83,14 @@ class TestTrainSteps:
             from repro.configs import get_smoke_config
             from repro.data.tokens import TokenPipeline
             from repro.launch import compat
-            from repro.launch.mesh import make_host_mesh, set_mesh
+            from repro.launch.mesh import make_host_mesh
             from repro.launch import sharding_rules as rules
             from repro.launch.steps import make_sync_train_step
             from repro.models import transformer as tf
             from repro.optim.optimizers import OptimizerConfig, get_optimizer
             cfg = get_smoke_config("qwen2-1.5b")
             mesh = make_host_mesh(8, model=2)
-            set_mesh(mesh)
+            jax.set_mesh(mesh)
             params = tf.init_params(cfg, jax.random.PRNGKey(0))
             opt_init, _ = get_optimizer("adamw", OptimizerConfig(lr=1e-3))
             opt = opt_init(params)
@@ -130,14 +130,14 @@ class TestTrainSteps:
             from repro.configs import get_smoke_config
             from repro.data.tokens import TokenPipeline
             from repro.launch import compat
-            from repro.launch.mesh import make_host_mesh, set_mesh
+            from repro.launch.mesh import make_host_mesh
             from repro.launch import sharding_rules as rules
             from repro.launch.steps import (LGCStepConfig, init_ef_tree,
                                             make_lgc_train_step)
             from repro.models import transformer as tf
             cfg = get_smoke_config("qwen2-1.5b")
             mesh = make_host_mesh(8, model=1)
-            set_mesh(mesh)
+            jax.set_mesh(mesh)
             params = tf.init_params(cfg, jax.random.PRNGKey(0))
             lgc = LGCStepConfig(local_steps=2, local_lr=5e-3,
                                 sparsity=(0.02, 0.03),
@@ -182,13 +182,13 @@ class TestServing:
             import jax, jax.numpy as jnp
             from repro.configs import get_smoke_config
             from repro.launch import compat
-            from repro.launch.mesh import make_host_mesh, set_mesh
+            from repro.launch.mesh import make_host_mesh
             from repro.launch import sharding_rules as rules
             from repro.launch.steps import make_serve_step
             from repro.models import transformer as tf
             cfg = get_smoke_config("zamba2-1.2b")
             mesh = make_host_mesh(8, model=2)
-            set_mesh(mesh)
+            jax.set_mesh(mesh)
             params = tf.init_params(cfg, jax.random.PRNGKey(0))
             b = 8
             cache = tf.init_cache(cfg, b, 64)

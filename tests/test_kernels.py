@@ -196,3 +196,20 @@ class TestSWADecode:
         out2 = swa_decode(q, k2, v2, ln, chunk=64)
         np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                    rtol=1e-6)
+
+
+class TestInterpretMode:
+    """The platform, not a flag, decides how the kernels run."""
+
+    @pytest.mark.parametrize("platform,mode", [("cpu", True), ("tpu", False)])
+    def test_platform_picks_the_mode(self, monkeypatch, platform, mode):
+        from repro.kernels.platform import resolve_interpret
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert resolve_interpret() is mode
+        assert resolve_interpret(not mode) is (not mode)   # explicit wins
+
+    def test_unknown_platform_raises(self, monkeypatch):
+        from repro.kernels.platform import resolve_interpret
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(ValueError, match="'gpu'"):
+            resolve_interpret()

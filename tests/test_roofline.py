@@ -63,7 +63,7 @@ class TestHloCostModel:
             from repro.analysis.hlo_cost import analyze_hlo
             from repro.launch import compat
             mesh = compat.make_mesh((8,), ("data",))
-            compat.set_mesh(mesh)
+            jax.set_mesh(mesh)
             def f(x, w):
                 return jnp.sum(x @ w)
             x = jax.ShapeDtypeStruct((512, 256), jnp.float32)
