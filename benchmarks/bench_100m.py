@@ -66,9 +66,8 @@ def _point(aggregate: str, sparsity: tuple, preset: str, m_devices: int,
         "task": "qwen2_100m", "preset": preset, "aggregate": aggregate,
         "sparsity": "+".join(f"{f:g}" for f in sparsity),
         "m_devices": m_devices, "rounds": rounds,
-        "param_count": out["param_count"],
-        "wire_bytes_per_round_per_device":
-            out["wire_bytes_per_round_per_device"],
+        "param_count": task.param_count(),
+        "wire_bytes_per_round_per_device": task.wire_bytes_per_round(),
         "device_steps_per_s": round(out["device_steps_per_s"], 3),
         "first_loss": round(losses[0], 4),
         "last_loss": round(losses[-1], 4),

@@ -102,7 +102,7 @@ def phase_qwen_rounds(aggregate: str, backend: str, rounds: int = 4,
     return {
         "phase": f"qwen2_100m {aggregate} {backend}",
         "m_devices": m_devices, "rounds": rounds,
-        "param_count": out["param_count"], "losses": losses,
+        "param_count": task.param_count(), "losses": losses,
         "compile_and_first_round_s": out["first_round_s"],
         "steady_round_s": out["steady_round_s"],
         "tpu_custom_call": "tpu_custom_call" in _compiled_step(task).as_text(),

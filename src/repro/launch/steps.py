@@ -120,6 +120,17 @@ def make_sync_train_step(cfg: ArchConfig, *, accum_steps: int = 1,
 # LGC training step (Algorithm 1 on the mesh)
 # ---------------------------------------------------------------------------
 
+# Named scopes of the step's phases.  They only write HLO metadata (each
+# op's ``op_name`` path), so a profiler trace can credit device time to a
+# phase; the innermost ``lgc.*`` component of the path decides.
+SCOPE_LOCAL_SGD = "lgc.local_sgd"          # the H-step scan
+SCOPE_COMPRESS = "lgc.compress"            # delta, per-leaf LGC, wire cast
+SCOPE_EXCHANGE = "lgc.exchange"            # every cross-device reduction
+SCOPE_SERVER_UPDATE = "lgc.server_update"  # new weights, new error memory
+PHASE_SCOPES = (SCOPE_LOCAL_SGD, SCOPE_COMPRESS, SCOPE_EXCHANGE,
+                SCOPE_SERVER_UPDATE)
+
+
 def _leaf_ks(size: int, sparsity: Sequence[float]) -> list[int]:
     """Per-channel k budgets, cumulatively clamped to the leaf size.
 
@@ -238,8 +249,9 @@ def _compress_leaf_sparse(e: Array, delta: Array, sparsity, recv: Array,
         # (I-C5: re-pin the gathered buffers to the model axis -- the
         # all_gather result otherwise materialises replicated per chip,
         # which is what kept xpod at the unsharded size in I-C4)
-        vals_all = jax.lax.all_gather(vals, fl_ax)         # (n_fl, rows, k)
-        idx_all = jax.lax.all_gather(idx, fl_ax)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            vals_all = jax.lax.all_gather(vals, fl_ax)     # (n_fl, rows, k)
+            idx_all = jax.lax.all_gather(idx, fl_ax)
         if ax is not None:
             vals_all = maybe_constrain(vals_all, None, "model", None)
             idx_all = maybe_constrain(idx_all, None, "model", None)
@@ -310,8 +322,9 @@ def _compress_leaf_bucket(e: Array, delta: Array, sparsity, recv: Array,
         v_c = vals[:, lo:hi] * recv[c].astype(jnp.float32)
         i_c = idx[:, lo:hi]
         g_own = jax.vmap(lambda g, i, v: g.at[i].add(v))(g_own, i_c, v_c)
-        v_all = jax.lax.all_gather(v_c, fl_ax)             # (n_fl, rows, k_c)
-        i_all = jax.lax.all_gather(i_c, fl_ax)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            v_all = jax.lax.all_gather(v_c, fl_ax)         # (n_fl, rows, k_c)
+            i_all = jax.lax.all_gather(i_c, fl_ax)
         for fl in range(n_fl):
             g_sum = jax.vmap(lambda g, i, v: g.at[i].add(v)
                              )(g_sum, i_all[fl], v_all[fl])
@@ -381,6 +394,48 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
     dense_kw = dict(backend=step_cfg.backend,
                     pallas_min_elems=step_cfg.pallas_min_elems)
 
+    def compress(ef, delta, recv):
+        """(g_mean, ef_new): the server's mean update and this device's new
+        error memory, by aggregate mode."""
+        def split(pairs):
+            pick = lambda i: jax.tree_util.tree_map(
+                lambda t: t[i], pairs, is_leaf=lambda t: isinstance(t, tuple))
+            return pick(0), pick(1)
+
+        if step_cfg.aggregate == "none":              # FedAvg baseline
+            with jax.named_scope(SCOPE_EXCHANGE):
+                return jax.tree_util.tree_map(
+                    lambda dl: jax.lax.pmean(dl, fl_ax), delta), ef
+        if step_cfg.aggregate in ("bucket_sparse", "sparse_gather"):
+            leaf_fn = (_compress_leaf_bucket
+                       if step_cfg.aggregate == "bucket_sparse"
+                       else _compress_leaf_sparse)
+            if param_spec_tree is not None:
+                return split(jax.tree_util.tree_map(
+                    lambda e, dl, sp: leaf_fn(
+                        e, dl, step_cfg.sparsity, recv, fl_ax, n_fl, sp),
+                    ef, delta, param_spec_tree))
+            return split(jax.tree_util.tree_map(
+                lambda e, dl: leaf_fn(
+                    e, dl, step_cfg.sparsity, recv, fl_ax, n_fl),
+                ef, delta))
+        # dense_masked
+        g, ef_new = split(jax.tree_util.tree_map(
+            lambda e, dl: _compress_leaf_dense(
+                e, dl, step_cfg.sparsity, recv, **dense_kw),
+            ef, delta))
+        wire_dt = jnp.dtype(step_cfg.psum_dtype)
+        g_wire = jax.tree_util.tree_map(lambda gl: gl.astype(wire_dt), g)
+        # quantisation residue joins the error memory (I-C7)
+        ef_new = jax.tree_util.tree_map(
+            lambda en, gl, gw: en + (gl - gw.astype(jnp.float32)),
+            ef_new, g, g_wire)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            g_mean = jax.tree_util.tree_map(
+                lambda gw: jax.lax.pmean(gw, fl_ax).astype(jnp.float32),
+                g_wire)
+        return g_mean, ef_new
+
     def step(params, ef, batch, received=None):
         if received is None:
             received = jnp.ones((n_fl, n_ch), jnp.int32)
@@ -391,15 +446,14 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
             out_specs=(P(), P(fl_ax), P()),
             axis_names=manual)
         def inner(params, ef_stack, batch, received):
-            ef = jax.tree_util.tree_map(lambda x: x[0], ef_stack)
-            recv = received[0].astype(jnp.int32)      # (C,) own channels
+            with jax.named_scope(SCOPE_COMPRESS):
+                ef = jax.tree_util.tree_map(lambda x: x[0], ef_stack)
+                recv = received[0].astype(jnp.int32)  # (C,) own channels
             # ---- H local SGD steps (Alg. 1 line 6) -----------------------
             b_local = jax.tree_util.tree_leaves(batch)[0].shape[0]
             assert b_local % h == 0 and b_local >= h, (
                 f"per-FL-device batch {b_local} must be divisible by "
                 f"local_steps H={h}")
-            mbs = jax.tree_util.tree_map(
-                lambda x: x.reshape(h, x.shape[0] // h, *x.shape[1:]), batch)
 
             def local_sgd(carry, mb):
                 p, loss_sum = carry
@@ -411,82 +465,30 @@ def make_lgc_train_step(cfg: ArchConfig, mesh, step_cfg: LGCStepConfig,
                     p, g)
                 return (p, loss_sum + l), None
 
-            (p_end, loss_sum), _ = jax.lax.scan(
-                local_sgd, (params, jnp.float32(0.0)), mbs)
-            loss = jax.lax.pmean(loss_sum / h, fl_ax)
+            with jax.named_scope(SCOPE_LOCAL_SGD):
+                mbs = jax.tree_util.tree_map(
+                    lambda x: x.reshape(h, x.shape[0] // h, *x.shape[1:]),
+                    batch)
+                (p_end, loss_sum), _ = jax.lax.scan(
+                    local_sgd, (params, jnp.float32(0.0)), mbs)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                loss = jax.lax.pmean(loss_sum / h, fl_ax)
 
             # ---- net progress + error feedback + LGC (lines 8-11) -------
-            delta = jax.tree_util.tree_map(
-                lambda w0, w1: (w0.astype(jnp.float32)
-                                - w1.astype(jnp.float32)), params, p_end)
-
-            if step_cfg.aggregate == "none":          # FedAvg baseline
-                g_mean = jax.tree_util.tree_map(
-                    lambda dl: jax.lax.pmean(dl, fl_ax), delta)
-                ef_new = ef
-            elif step_cfg.aggregate == "bucket_sparse":
-                if param_spec_tree is not None:
-                    pairs = jax.tree_util.tree_map(
-                        lambda e, dl, sp: _compress_leaf_bucket(
-                            e, dl, step_cfg.sparsity, recv, fl_ax, n_fl, sp),
-                        ef, delta, param_spec_tree)
-                else:
-                    pairs = jax.tree_util.tree_map(
-                        lambda e, dl: _compress_leaf_bucket(
-                            e, dl, step_cfg.sparsity, recv, fl_ax, n_fl),
-                        ef, delta)
-                g_mean = jax.tree_util.tree_map(
-                    lambda t: t[0], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                ef_new = jax.tree_util.tree_map(
-                    lambda t: t[1], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-            elif step_cfg.aggregate == "sparse_gather":
-                if param_spec_tree is not None:
-                    pairs = jax.tree_util.tree_map(
-                        lambda e, dl, sp: _compress_leaf_sparse(
-                            e, dl, step_cfg.sparsity, recv, fl_ax, n_fl, sp),
-                        ef, delta, param_spec_tree)
-                else:
-                    pairs = jax.tree_util.tree_map(
-                        lambda e, dl: _compress_leaf_sparse(
-                            e, dl, step_cfg.sparsity, recv, fl_ax, n_fl),
-                        ef, delta)
-                g_mean = jax.tree_util.tree_map(
-                    lambda t: t[0], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                ef_new = jax.tree_util.tree_map(
-                    lambda t: t[1], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-            else:                                      # dense_masked
-                pairs = jax.tree_util.tree_map(
-                    lambda e, dl: _compress_leaf_dense(
-                        e, dl, step_cfg.sparsity, recv, **dense_kw),
-                    ef, delta)
-                g = jax.tree_util.tree_map(
-                    lambda t: t[0], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                ef_new = jax.tree_util.tree_map(
-                    lambda t: t[1], pairs,
-                    is_leaf=lambda t: isinstance(t, tuple))
-                wire_dt = jnp.dtype(step_cfg.psum_dtype)
-                g_wire = jax.tree_util.tree_map(
-                    lambda gl: gl.astype(wire_dt), g)
-                # quantisation residue joins the error memory (I-C7)
-                ef_new = jax.tree_util.tree_map(
-                    lambda en, gl, gw: en + (gl - gw.astype(jnp.float32)),
-                    ef_new, g, g_wire)
-                g_mean = jax.tree_util.tree_map(
-                    lambda gw: jax.lax.pmean(gw, fl_ax).astype(jnp.float32),
-                    g_wire)
+            with jax.named_scope(SCOPE_COMPRESS):
+                delta = jax.tree_util.tree_map(
+                    lambda w0, w1: (w0.astype(jnp.float32)
+                                    - w1.astype(jnp.float32)), params, p_end)
+                g_mean, ef_new = compress(ef, delta, recv)
 
             # ---- server update + broadcast (lines 20-21, 12) -------------
-            params_new = jax.tree_util.tree_map(
-                lambda w, gm: (w.astype(jnp.float32) - gm).astype(w.dtype),
-                params, g_mean)
-            ef_new = jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.dtype(step_cfg.ef_dtype))[None],
-                ef_new)
+            with jax.named_scope(SCOPE_SERVER_UPDATE):
+                params_new = jax.tree_util.tree_map(
+                    lambda w, gm: (w.astype(jnp.float32) - gm).astype(w.dtype),
+                    params, g_mean)
+                ef_new = jax.tree_util.tree_map(
+                    lambda x: x.astype(jnp.dtype(step_cfg.ef_dtype))[None],
+                    ef_new)
             return params_new, ef_new, loss
 
         return inner(params, ef, batch, received)

@@ -45,13 +45,23 @@ from repro.models import transformer as tf
 
 Array = jax.Array
 
+# host spans of one sync round in ``LGCTransformerTask.run``
+SPAN_ROUND = "lgc.round"        # the round; step_num is its index
+SPAN_MASK = "lgc.mask"          # the mask program (and its initial state)
+SPAN_BATCH = "lgc.batch"        # next_batch and its two transfers
+SPAN_STEP = "lgc.step"          # the step's dispatch
+SPAN_READBACK = "lgc.readback"  # float(loss): waits for the step
+ROUND_SPANS = (SPAN_MASK, SPAN_BATCH, SPAN_STEP, SPAN_READBACK)
+
 
 @dataclasses.dataclass
 class LGCTransformerTask:
     """A registry task backed by the shard_map LGC train step.
 
     ``build()`` constructs the mesh/params/step once; ``run(steps)``
-    drives training and returns the loss trajectory plus wire accounting.
+    drives training and returns the loss trajectory and throughput;
+    ``param_count()`` and ``wire_bytes_per_round()`` give the model size
+    and the per-device uplink bytes.
     """
     arch: ArchConfig
     m_devices: int
@@ -148,23 +158,39 @@ class LGCTransformerTask:
     # -- training -----------------------------------------------------------
 
     def run(self, steps: int, log_every: int = 0) -> dict:
-        """Train for ``steps`` sync rounds; returns losses + throughput +
-        wire accounting (the bench consumes this directly)."""
+        """Train for ``steps`` sync rounds; returns the losses and the
+        throughput (the bench consumes this directly).
+
+        Each round is a profiler step span ``lgc.round`` (its step number
+        is the round's index) holding four host spans in order:
+        ``lgc.mask`` (the delivery mask), ``lgc.batch`` (the next batch),
+        ``lgc.step`` (the step's dispatch) and ``lgc.readback`` (the loss,
+        which syncs the round); the channel chains' initial state, made
+        once per call before the first round, is a ``lgc.mask`` span of
+        its own.  They cost a few microseconds a round when no profiler
+        runs."""
         b = self.build()
         params, ef, step, pipe = b["params"], b["ef"], b["step"], b["pipe"]
-        base, dev_ids, carry = self._mask_state()
+        with jax.profiler.TraceAnnotation(SPAN_MASK):
+            base, dev_ids, carry = self._mask_state()
         losses, t_steady, first_round_s = [], None, 0.0
         t0 = time.perf_counter()
         for i in range(steps):
-            carry, received = self._round_mask(base, dev_ids, carry, i)
-            # the mask program's output is committed replicated; the step
-            # takes it split over the FL axis
-            received = jax.device_put(received, b["recv_sharding"])
-            x, y = pipe.next_batch()
-            params, ef, loss = step(params, ef,
-                                    {"tokens": jnp.asarray(x),
-                                     "labels": jnp.asarray(y)}, received)
-            losses.append(float(loss))   # float() syncs the step
+            with jax.profiler.StepTraceAnnotation(SPAN_ROUND, step_num=i):
+                with jax.profiler.TraceAnnotation(SPAN_MASK):
+                    carry, received = self._round_mask(base, dev_ids, carry,
+                                                       i)
+                    # the mask program's output is committed replicated;
+                    # the step takes it split over the FL axis
+                    received = jax.device_put(received, b["recv_sharding"])
+                with jax.profiler.TraceAnnotation(SPAN_BATCH):
+                    x, y = pipe.next_batch()
+                    batch = {"tokens": jnp.asarray(x),
+                             "labels": jnp.asarray(y)}
+                with jax.profiler.TraceAnnotation(SPAN_STEP):
+                    params, ef, loss = step(params, ef, batch, received)
+                with jax.profiler.TraceAnnotation(SPAN_READBACK):
+                    losses.append(float(loss))   # float() syncs the step
             if i == 0:
                 t_steady = time.perf_counter()   # exclude compile
                 first_round_s = t_steady - t0
@@ -182,8 +208,6 @@ class LGCTransformerTask:
             "first_round_s": first_round_s,
             "steady_round_s": steady_s / (steps - 1) if steps > 1 else 0.0,
             "device_steps_per_s": (dev_steps / steady_s) if steady_s else 0.0,
-            "wire_bytes_per_round_per_device": self.wire_bytes_per_round(),
-            "param_count": self.param_count(),
         }
 
 
