@@ -36,6 +36,7 @@ from repro.launch import compat
 from repro.launch.mesh import fl_axis_name, make_host_mesh
 from repro.launch.steps import (_compress_leaf_bucket, _compress_leaf_dense,
                                 _compress_leaf_sparse, _leaf_ks,
+                                _row_thresholds,
                                 lgc_wire_bytes_per_round, LGCStepConfig)
 from repro.models.lgc_transformer import make_qwen2_100m_task
 from repro.models.paper_models import ENGINE_TASKS, TASKS, make_task
@@ -250,6 +251,96 @@ class TestLeafLevelSelection:
             # n_fl=1: g_mean == g_own, so the identity is directly checkable
             np.testing.assert_allclose(np.asarray(g) + np.asarray(e_new),
                                        tot, atol=1e-6, err_msg=name)
+
+
+def _row_leaf(case: str) -> tuple[np.ndarray, tuple]:
+    """(rows, cols) f32 leaf and sparsity ladder of one threshold case."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    ladder = (0.01, 0.02, 0.02)
+    if case == "one_row":
+        u = rng.normal(size=(1, 3000))
+    elif case == "many_rows":
+        u = rng.normal(size=(37, 1000)) * rng.uniform(0.1, 10, (37, 1))
+    elif case == "narrow":                 # under 256, not a multiple of 128
+        u, ladder = rng.normal(size=(5, 200)), (0.05, 0.1, 0.1)
+    elif case == "zero_row":               # maxabs 0: every threshold 0
+        u = rng.normal(size=(6, 700))
+        u[2] = 0.0
+    elif case == "bin_edges":              # magnitudes exactly on bin edges
+        mx = rng.uniform(0.5, 4.0, (8, 1))
+        u = (rng.integers(0, 257, (8, 640)) * (mx / kref.N_BINS)
+             * rng.choice([-1.0, 1.0], (8, 640)))
+        ladder = (0.1, 0.2, 0.2)
+    elif case == "half_zero":
+        u = rng.normal(size=(9, 512))
+        u[:, rng.permutation(512)[:256]] = 0.0
+        ladder = (0.2, 0.3, 0.3)           # budgets reach into the zeros
+    elif case == "clamped":                # cumulative budget clamped to cols
+        u, ladder = rng.normal(size=(4, 10)), SATURATING
+    elif case == "wide_range":             # magnitudes 1e-6 .. 1e2
+        u = (10.0 ** rng.uniform(-6, 2, (12, 2048))
+             * rng.choice([-1.0, 1.0], (12, 2048)))
+    return u.astype(np.float32), ladder
+
+
+class TestRowThresholds:
+    """The sparse path's per-row threshold bisection (``_row_thresholds``)
+    against the histogram oracle it replaces, bit for bit."""
+
+    @pytest.mark.parametrize("case", ["one_row", "many_rows", "narrow",
+                                      "zero_row", "bin_edges", "half_zero",
+                                      "clamped", "wide_range"])
+    def test_bisection_matches_histogram_bitwise(self, case):
+        u, ladder = _row_leaf(case)
+        cum = jnp.asarray(np.cumsum(_leaf_ks(u.shape[1], ladder)), jnp.int32)
+        if case == "clamped":
+            assert int(cum[-1]) == u.shape[1]
+
+        @jax.jit
+        def oracle(u):
+            mx = jax.vmap(kref.hist_maxabs)(u)
+            counts = jax.vmap(kref.hist_counts)(u, mx)
+            return jax.vmap(lambda c, m: kref.hist_thresholds(c, m, cum)
+                            )(counts, mx)
+
+        @jax.jit
+        def search(u):
+            return _row_thresholds(u, jax.vmap(kref.hist_maxabs)(u), cum)
+
+        want = np.asarray(oracle(jnp.asarray(u)))
+        got = np.asarray(search(jnp.asarray(u)))
+        assert got.shape == want.shape == (u.shape[0], cum.shape[0])
+        np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+        if case == "zero_row":
+            assert not want[2].any()
+
+    def test_sparse_leaf_has_no_integer_scatter(self):
+        """The vmapped histogram was a scatter-add into int32 counts (on a
+        TPU: a sort of every bin index plus a scatter).  Only the f32
+        scatters of g_own and g_sum may remain."""
+        rows, cols, ladder = 64, 1024, (0.01, 0.02, 0.02)
+        mesh = make_host_mesh(1)
+        fl_ax = fl_axis_name(mesh)
+        f = compat.shard_map(
+            lambda e_, d_, r_: _compress_leaf_sparse(
+                e_, d_, ladder, r_, fl_ax, 1, spec=P("model", None)),
+            mesh=mesh, in_specs=(P(), P(), P()), out_specs=(P(), P()),
+            axis_names={fl_ax})
+        leaf = jax.ShapeDtypeStruct((rows, cols), jnp.float32)
+        jaxpr = jax.make_jaxpr(f)(leaf, leaf,
+                                  jax.ShapeDtypeStruct((3,), jnp.int32))
+
+        def scatters(jx):
+            for eqn in jx.eqns:
+                if eqn.primitive.name.startswith("scatter"):
+                    yield eqn.primitive.name, eqn.invars[0].aval.dtype
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scatters(sub)
+
+        found = list(scatters(jaxpr.jaxpr))
+        assert found, "g_own/g_sum scatters should be in the jaxpr"
+        assert all(dt == jnp.float32 for _, dt in found), found
+        assert {name for name, _ in found} == {"scatter-add"}
 
 
 class TestDeliveryMaskFreeze:
