@@ -1,7 +1,8 @@
 """A benchmark cell, found by name, and the system under test built for it.
 
 ``load_cell`` reads ``BENCHMARK.json`` and the cell's configuration,
-traffic and limit files.  ``build_task`` turns them into the program's
+traffic and limit files; the configuration names its model family
+(``families/``).  ``build_task`` turns them into the program's
 ``make_task("qwen2_100m", arch=...)`` task, whose ``run(n)`` is the timed
 entry.  The weights and the token stream are the benchmark's own, made
 from the seed (``weights.py``, ``feed.py``) and handed to the built task.
@@ -12,17 +13,10 @@ import dataclasses
 import json
 import pathlib
 
+from chipbench import families
+
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 HERE = pathlib.Path(__file__).resolve().parent
-
-#: keys of a configuration file that the program's ArchConfig takes, by the
-#: name the published config.json gives them
-_ARCH_KEYS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,11 +59,11 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
 
 
 def arch_config(config: dict):
-    """The program's ArchConfig for a configuration file."""
+    """The program's ArchConfig for a configuration file: its model
+    family's keywords, the file's ``program`` dict on top."""
     from repro.configs.base import ArchConfig
-    kw = {ours: config[theirs] for theirs, ours in _ARCH_KEYS.items()}
-    return ArchConfig(name=config["name"], arch_type="dense",
-                      **kw, **config["program"])
+    kw = families.load(config).arch_kwargs(config) | config["program"]
+    return ArchConfig(name=config["name"], **kw)
 
 
 def make_task(cell: Cell, seed: int):

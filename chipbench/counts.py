@@ -5,12 +5,15 @@ Model FLOPs follow PaLM (arXiv:2204.02311) appendix B: ``6 N + 12 L H Q T``
 per trained token, N the parameters that multiply an activation (the input
 embedding excluded, the LM head included, a tied head counted once as the
 head), L layers, H query heads of width Q, T the sequence length.
-Recomputed (rematerialised) work is not counted.
+Recomputed (rematerialised) work is not counted.  The configuration's
+model family (``families/``) makes the count.
 """
 from __future__ import annotations
 
 import json
 import pathlib
+
+from chipbench import families
 
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
@@ -34,20 +37,12 @@ def padded_vocab(vocab: int) -> int:
 
 
 def matmul_params(config: dict) -> int:
-    """N of PaLM's count: every weight an activation multiplies."""
-    d, f = config["hidden_size"], config["intermediate_size"]
-    nh, nkv = config["num_attention_heads"], config["num_key_value_heads"]
-    hd = d // nh
-    attn = 2 * d * nh * hd + 2 * d * nkv * hd
-    mlp = (3 if config["hidden_act"] == "silu" else 2) * d * f
-    head = d * padded_vocab(config["vocab_size"])
-    return config["num_hidden_layers"] * (attn + mlp) + head
+    """N of PaLM's count, as the configuration's model family counts it."""
+    return families.load(config).matmul_params(config)
 
 
 def model_flops_per_token(config: dict, seq: int) -> int:
-    n_l, nh = config["num_hidden_layers"], config["num_attention_heads"]
-    hd = config["hidden_size"] // nh
-    return 6 * matmul_params(config) + 12 * n_l * nh * hd * seq
+    return families.load(config).model_flops_per_token(config, seq)
 
 
 def compress_bytes(leaf_sizes, min_elems: int) -> int:

@@ -1,0 +1,8 @@
+"""Device self time per round of the ops whose innermost ``lgc.*`` scope is
+``lgc.local_sgd``: the H local steps' forward and backward, mean over the
+chips.  None where the trace was read without the step's scopes."""
+
+
+def read(ctx):
+    s = ctx.view.innermost_s("lgc.").get("lgc.local_sgd", 0.0)
+    return 1e3 * s / ctx.rounds if s > 0 else None

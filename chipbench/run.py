@@ -65,15 +65,23 @@ class Recorder:
     the step returns: after round 1 the update the server applied and the
     device's whole progress (that update plus the new error memory, mean
     over the FL devices), after round 3 the change from the initial
-    weights."""
+    weights; and the shapes and shardings of the step's arguments, for its
+    compiled text."""
 
     def __init__(self, step, seed: int, sigma: float):
         self.step, self.seed, self.sigma = step, seed, sigma
         self.masks, self.update, self.progress, self.change = [], None, None, None
+        self.arg_shapes = None
 
     def __call__(self, params, ef, batch, received):
+        import jax
         import numpy as np
         from chipbench import weights as W
+        if self.arg_shapes is None:
+            self.arg_shapes = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding),
+                (params, ef, batch, received))
         self.masks.append(np.asarray(received))
         params, ef, loss = self.step(params, ef, batch, received)
         r = len(self.masks)
@@ -96,6 +104,13 @@ def _device_info(jax, chips: int) -> dict:
         peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs), "memory_peak_bytes": peak}
+
+
+def step_hlo(step, arg_shapes) -> str:
+    """The compiled text of the timed step: lowered again for the shapes
+    and shardings of its arguments, which finds in JAX's caches the
+    executable the window ran."""
+    return step.lower(*arg_shapes).compile().as_text()
 
 
 def _per_layer(cell, bench: dict, ctx) -> dict:
@@ -165,7 +180,7 @@ def run_cell(cell, bench: dict, seed: int, seconds: float, trace: bool, *,
     """One run of ``cell``; returns the result object."""
     import jax
     import numpy as np
-    from chipbench import counts, reference
+    from chipbench import counts, reference, weights as W
     from chipbench import trace as T
 
     counter = CompileCounter()
@@ -187,6 +202,7 @@ def run_cell(cell, bench: dict, seed: int, seconds: float, trace: bool, *,
         t0 = time.perf_counter()
         out = task.run(n)
         wall = time.perf_counter() - t0
+        log(f"backend compiles inside the window: {counter.n - c0}")
         tokens_s = n * cell.tokens_per_round / wall
         metrics["tokens_per_s"] = {"value": tokens_s, "unit": "tokens/s"}
         if peak is not None:
@@ -203,18 +219,21 @@ def run_cell(cell, bench: dict, seed: int, seconds: float, trace: bool, *,
             with jax.profiler.TraceAnnotation(T.WINDOW):
                 out = task.run(n)
             jax.profiler.stop_trace()
-            view = T.load(d, chips=cell.chips)
-        leaf_sizes = [int(np.prod(s.shape))
-                      for s in jax.tree_util.tree_leaves(shapes)]
+            log(f"backend compiles inside the window: {counter.n - c0}")
+            view = T.load(d, chips=cell.chips,
+                          hlo=step_hlo(task._built["step"], rec.arg_shapes))
+        leaves = tuple((name, tuple(s.shape)) for name, s in zip(
+            W.leaf_names(shapes), jax.tree_util.tree_leaves(shapes)))
         ctx = T.Context(view=view, rounds=n, chips=cell.chips, peak=peak,
                         flops_per_round=flops_round,
                         compress_bytes_per_round=cell.traffic["fl_devices"]
                         * counts.compress_bytes(
-                            leaf_sizes, task.step_cfg.pallas_min_elems))
+                            [int(np.prod(shape)) for _, shape in leaves],
+                            task.step_cfg.pallas_min_elems),
+                        cell=cell, leaves=leaves)
         metrics = _per_layer(cell, bench, ctx)
         extra["busy_s"], extra["window_s"] = view.busy_s(), view.window_s()
         breakdown = view.breakdown()
-    log(f"backend compiles inside the window: {counter.n - c0}")
     failed = int(np.sum(~np.isfinite(out["losses"])))
     device = _device_info(jax, cell.chips) | extra
     if not trace:

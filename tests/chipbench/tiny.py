@@ -1,12 +1,9 @@
-"""A cell's traffic and limits at a width a CPU test can hold."""
+"""A cell's traffic and limits at a width a CPU test can hold: its model
+family's ``TINY`` widths."""
 import dataclasses
 
 from chipbench import cell as C
-
-TINY_WIDTHS = dict(hidden_size=64, intermediate_size=128,
-                   num_attention_heads=4, num_key_value_heads=2,
-                   num_hidden_layers=2, vocab_size=512)
-
+from chipbench import families
 
 #: a leaf of a tiny model holds few elements, so which of them cross a
 #: threshold moves its norm more than at a cell's widths: the norm gaps are
@@ -20,5 +17,6 @@ def tiny_cell(name: str) -> C.Cell:
              sequences_per_device=2 * c.traffic["local_steps"])
     limits = {k: (v if k == "loss_gap" else TINY_NORM_LIMIT)
               for k, v in c.limits.items()}
-    return dataclasses.replace(c, config=dict(c.config, **TINY_WIDTHS),
-                               traffic=t, limits=limits)
+    return dataclasses.replace(
+        c, config=dict(c.config, **families.load(c.config).TINY),
+        traffic=t, limits=limits)
