@@ -17,44 +17,45 @@ from chipbench import weights as W
 from tiny import tiny_cell
 
 CELLS = [w["name"] for w in C.load_benchmark()["workloads"]]
-DENSE = pathlib.Path(families.__file__).resolve().parent / "dense.py"
 
 
 @pytest.fixture
-def copied_family(tmp_path, monkeypatch):
-    """The dense family again, as a file ``dense_copy.py`` outside the
-    package's directory."""
-    (tmp_path / "dense_copy.py").write_text(DENSE.read_text())
+def copied_family(name, tmp_path, monkeypatch):
+    """The family module of cell ``name`` again, as a file under a new
+    name outside the package's directory."""
+    own = pathlib.Path(families.load(C.load_cell(name).config).__file__)
+    copy = f"{own.stem}_copy"
+    (tmp_path / f"{copy}.py").write_text(own.read_text())
     monkeypatch.setattr(families, "__path__",
                         [*families.__path__, str(tmp_path)])
-    yield "dense_copy"
-    sys.modules.pop(f"{families.__name__}.dense_copy", None)
+    yield copy
+    sys.modules.pop(f"{families.__name__}.{copy}", None)
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_a_family_in_a_new_file_reads_as_dense(name, copied_family):
     cell = tiny_cell(name)
-    dense = cell.config
-    other = dict(dense, family=copied_family)
+    own = cell.config
+    other = dict(own, family=copied_family)
     assert families.load(other).__name__.endswith(copied_family)
-    assert C.arch_config(other) == C.arch_config(dense)
-    assert counts.matmul_params(other) == counts.matmul_params(dense)
+    assert C.arch_config(other) == C.arch_config(own)
+    assert counts.matmul_params(other) == counts.matmul_params(own)
     assert counts.model_flops_per_token(other, 2048) == \
-        counts.model_flops_per_token(dense, 2048)
+        counts.model_flops_per_token(own, 2048)
     seed = 2_147_483_663
     shapes = control.shapes_of(cell)
-    params = W.make(shapes, seed, dense["initializer_range"])
+    params = W.make(shapes, seed, own["initializer_range"])
     tokens = np.random.default_rng(seed).integers(
-        0, dense["vocab_size"], (2, 33), dtype=np.int32)
+        0, own["vocab_size"], (2, 33), dtype=np.int32)
     x, y = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
     for path, leaf in zip(W.leaf_names(shapes),
                           jax.tree_util.tree_leaves(shapes)):
         assert families.load(other).row_axis(path, leaf.ndim) == \
-            families.load(dense).row_axis(path, leaf.ndim), path
+            families.load(own).row_axis(path, leaf.ndim), path
     losses = [reference.local_step(params, x, y,
                                    config=json.dumps(c, sort_keys=True),
                                    precision="f32", lr=3e-3)[0]
-              for c in (dense, other)]
+              for c in (own, other)]
     assert float(losses[0]) == float(losses[1])
     assert np.isfinite(float(losses[0]))
 

@@ -65,3 +65,11 @@ def test_sound_run_is_correct(name):
 def test_broken_step_is_not_correct(fault):
     res = _run(tiny_cell(CELLS[0]), fault)
     assert not res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("fault", [unchanged, half_batch, loss_altered,
+                                   weight_altered])
+@pytest.mark.parametrize("name", CELLS[1:])
+def test_broken_step_is_not_correct_in_the_other_cells(name, fault):
+    res = _run(tiny_cell(name), fault)
+    assert not res["correct"], res["checks"]
